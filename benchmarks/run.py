@@ -18,8 +18,11 @@ def _timeit(fn, iters=3):
 
 def main() -> None:
     from benchmarks import (area_analogue, context_switch, fig5_fus,
-                            multi_tenant, roofline, table1_schedule,
-                            table2_dfg, table3_area_tput)
+                            multi_tenant, table1_schedule, table2_dfg,
+                            table3_area_tput)
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
 
     print("== Table I: gradient schedule trace ==")
     t1 = _timeit(table1_schedule.main, 1)
@@ -35,12 +38,6 @@ def main() -> None:
     t5 = _timeit(area_analogue.main, 1)
     print("== Multi-tenant serving (context bank) ==")
     t7 = _timeit(multi_tenant.main, 1)
-    print("== Roofline (from dry-run artifacts) ==")
-    try:
-        t6 = _timeit(roofline.main, 1)
-    except Exception as e:
-        print(f"(roofline artifacts unavailable: {e})")
-        t6 = 0.0
     print("name,us_per_call,derived")
     print(f"table1_schedule,{t1:.0f},II=11")
     print(f"table2_dfg,{t2:.0f},8/8 exact")
@@ -49,7 +46,6 @@ def main() -> None:
     print(f"context_switch,{t4:.0f},worst ctx <0.35us @300MHz")
     print(f"area_analogue,{t5:.0f},tm executor vs spatial programs")
     print(f"multi_tenant,{t7:.0f},bank beats per-call load + recompile")
-    print(f"roofline,{t6:.0f},per-cell three-term table")
 
 
 if __name__ == "__main__":

@@ -173,6 +173,17 @@ class Overlay:
                  arena: RoundArena | None = None, donate: bool = False):
         if backend not in ("jnp", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
+        #: what executes the rounds: ``jnp`` (the VM), ``pallas-mosaic``
+        #: (the kernel compiled for a TPU) or ``pallas-interpret`` (the
+        #: Pallas interpreter, CPU devices only); fixed by the platform of
+        #: ``device`` (else the default device), never chosen silently
+        self.executor = "jnp"
+        if backend == "pallas":
+            from repro.kernels.tmfu.kernel import check_dtype
+            from repro.kernels.tmfu.ops import interpret_for
+            check_dtype(dtype)
+            self.executor = ("pallas-interpret" if interpret_for(device)
+                             else "pallas-mosaic")
         self.s_max = s_max
         self.dtype = dtype
         self.backend = backend
@@ -216,7 +227,7 @@ class Overlay:
                        device=self.device)
         if self.backend == "pallas":
             from repro.kernels.tmfu import ops as tmfu_ops
-            ys = tmfu_ops.tmfu_pipeline(ctx, x)
+            ys = tmfu_ops.tmfu_pipeline(ctx, x, device=self.device)
         else:
             ys = vm.vm_exec(ctx.tree(), ctx.out_idx, x)
         return [ys[i] for i in range(ctx.n_outputs)]
@@ -228,10 +239,15 @@ class Overlay:
 
         The bank's stacked arrays feed ``vm_exec_multi`` (or the Pallas
         multi kernel) so every resident kernel is reachable by slot id from
-        ONE compiled executable.
+        ONE compiled executable.  A bank the Pallas kernel cannot hold in
+        SMEM is refused here, before anything compiles.
         """
         ks = list(kernels)
-        bank = ContextBank(capacity or max(len(ks), 1), s_max=self.s_max,
+        capacity = capacity or max(len(ks), 1)
+        if self.backend == "pallas":
+            from repro.kernels.tmfu.kernel import check_bank
+            check_bank(capacity, self.s_max)
+        bank = ContextBank(capacity, s_max=self.s_max,
                            dtype=self.dtype, max_outputs=max_outputs,
                            device=self.device)
         for k in ks:

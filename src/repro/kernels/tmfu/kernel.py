@@ -8,9 +8,9 @@ Mapping of the paper's FU onto the TPU memory hierarchy:
   * Register file (32-entry RAM32M)    -> a (32, bt) VMEM scratch buffer;
     'bt' lanes execute the same instruction on independent kernel
     iterations (vectorized pipeline replication, paper Fig. 4).
-  * DSP48E1 + config bits, no decoder  -> jax.lax.switch branch table on the
-    5-bit opcode field; operands gathered by dynamic row index (the 5-bit
-    RF addresses).
+  * DSP48E1 + config bits, no decoder  -> a branch table on the 5-bit
+    opcode field (one predicated region per op); operands gathered by
+    dynamic row index (the 5-bit RF addresses).
   * Linear FU->FU interconnect         -> stage loop ping-ponging two VMEM
     buffers: stage s writes its full result stream, which IS stage s+1's
     register file (direct connection, no programmable routing).
@@ -31,8 +31,66 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.isa import IM_DEPTH, RF_DEPTH
+from repro.core.vm import S_MAX
 
 DEFAULT_BLOCK_BATCH = 512
+
+# --- SMEM budget of the multi-tenant kernel -------------------------------
+# Scalar prefetch copies the whole bank (four [N, S * IM] int32 arrays) and
+# the round's [G] tile-id vector into SMEM before the first grid step.  On
+# a TPU v5e core the compiler reports 1 MiB of SMEM ("Used 1.00M of 1.00M
+# smem") and keeps 1.1-1.6 KiB of it for itself.  SMEM tiles a 2-D array
+# in (8, 128) words, so the [N, 512] bank costs 4 * 512 * 4 = 8 KiB a
+# context with N rounded up to a multiple of 8 (the unflattened
+# [N, 16, 32] layout padded every 32-word row to 128 words: 32 KiB a
+# context, at most 31 contexts).
+#: SMEM of one TPU v5e core, in bytes
+SMEM_BYTES = 1 << 20
+#: SMEM the compiler keeps beside the prefetched operands (rounded up)
+SMEM_RESERVED_BYTES = 2 * 1024
+#: tiles per pallas_call; larger rounds launch in chunks of this many
+MAX_LAUNCH_TILES = 4096
+
+
+def bank_smem_bytes(capacity: int, s_max: int) -> int:
+    """SMEM the four flattened bank arrays take, tiled in (8, 128) words."""
+    rows = -(-capacity // 8) * 8
+    row_words = -(-s_max * IM_DEPTH // 128) * 128
+    return 4 * rows * row_words * 4
+
+
+def max_bank_capacity(s_max: int) -> int:
+    """Most contexts whose bank fits SMEM beside a full launch's tile ids."""
+    budget = SMEM_BYTES - SMEM_RESERVED_BYTES - 4 * MAX_LAUNCH_TILES
+    return budget // bank_smem_bytes(8, s_max) * 8
+
+
+#: largest bank the kernel serves at the default ``S_MAX``: 120 contexts.
+#: ``tests/test_chip_compile.py`` compiles it for v5e, and checks that one
+#: more context overflows SMEM at a full ``MAX_LAUNCH_TILES`` launch.
+MAX_BANK_CAPACITY = max_bank_capacity(S_MAX)
+
+
+def check_dtype(dtype) -> None:
+    """Refuse a dtype Mosaic cannot serve: the kernel slices one row of the
+    register file at a dynamic offset, which a packed (16-bit) dtype
+    cannot do."""
+    if jnp.dtype(dtype).itemsize != 4:
+        raise ValueError(
+            f"the Pallas TMFU kernel serves 32-bit dtypes only, not "
+            f"{jnp.dtype(dtype).name}: Mosaic cannot load one row of a "
+            f"packed register file at a dynamic offset; use backend='jnp'")
+
+
+def check_bank(capacity: int, s_max: int) -> None:
+    """Refuse, before anything is compiled, a bank SMEM cannot hold."""
+    fit = max_bank_capacity(s_max)
+    if capacity > fit:
+        raise ValueError(
+            f"a bank of {capacity} contexts (s_max={s_max}) does not fit "
+            f"the Pallas TMFU kernel's SMEM ({SMEM_BYTES} B, less "
+            f"{SMEM_RESERVED_BYTES} B reserve and {MAX_LAUNCH_TILES} tile "
+            f"ids): at most {fit} contexts; use backend='jnp' for more")
 
 
 def _branch_table(dtype):
@@ -69,48 +127,55 @@ def _branch_table(dtype):
     ]
 
 
-def _run_stages(pfx, op_ref, a_ref, b_ref, imm_ref, rf_a, rf_b,
+def _run_stages(word, op_ref, a_ref, b_ref, imm_ref, rf_a, rf_b,
                 *, n_stages: int, dtype):
     """Shared stage loop: run ``n_stages`` over the ping-pong RF buffers.
 
-    ``pfx`` prefixes every SMEM instruction fetch — ``()`` for the
-    single-context [S, IM] layout, ``(cid,)`` for the stacked multi-tenant
-    [N, S, IM] bank — so the two datapaths cannot drift apart.
+    ``word(s, i)`` is the SMEM index of stage ``s``'s instruction ``i`` —
+    ``(s, i)`` in the single-context [S, IM] layout, ``(cid, s * IM + i)``
+    in the flattened multi-tenant [N, S * IM] bank — so the two datapaths
+    cannot drift apart.
+
+    Stages run in pairs (rf_a -> rf_b, then rf_b -> rf_a), so each
+    instruction's source and destination buffers are static.  The opcode
+    dispatch is one flat ``pl.when`` per branch-table entry: exactly one
+    fires, so one branch executes, and Mosaic sees no nested region (an
+    18-way ``lax.switch`` nests one region per branch, which overflows the
+    TPU compiler's layout inference).
     """
     branches = _branch_table(dtype)
     is_float = jnp.issubdtype(dtype, jnp.floating)
+    bt = rf_a.shape[-1]
 
-    def stage_body(s, _):
-        # ping-pong: even stages read rf_a/write rf_b, odd the reverse
-        def instr_body(i, _):
-            va_a = pl.load(rf_a, (pl.ds(a_ref[(*pfx, s, i)], 1), slice(None)))
-            va_b = pl.load(rf_b, (pl.ds(a_ref[(*pfx, s, i)], 1), slice(None)))
-            vb_a = pl.load(rf_a, (pl.ds(b_ref[(*pfx, s, i)], 1), slice(None)))
-            vb_b = pl.load(rf_b, (pl.ds(b_ref[(*pfx, s, i)], 1), slice(None)))
-            even = s % 2 == 0
-            va = jnp.where(even, va_a, va_b)
-            vb = jnp.where(even, vb_a, vb_b)
-            raw = imm_ref[(*pfx, s, i)]
+    def run_stage(s, src, dst):
+        def instr_body(i, carry):
+            at = word(s, i)
+            va = src[pl.ds(a_ref[at], 1), :]
+            vb = src[pl.ds(b_ref[at], 1), :]
+            # the immediate is an SMEM scalar; Mosaic bitcasts vectors only
+            raw = jnp.broadcast_to(imm_ref[at], (1, bt))
             if is_float:
                 cv = jax.lax.bitcast_convert_type(
                     raw, jnp.float32).astype(dtype)
             else:
                 cv = raw.astype(dtype)
-            res = jax.lax.switch(op_ref[(*pfx, s, i)], branches, va, vb, cv)
+            opc = op_ref[at]
+            for code, fn in enumerate(branches):
+                @pl.when(opc == code)
+                def _(fn=fn):
+                    dst[pl.ds(i, 1), :] = fn(va, vb, cv)
+            return carry
 
-            @pl.when(even)
-            def _():
-                pl.store(rf_b, (pl.ds(i, 1), slice(None)), res)
+        jax.lax.fori_loop(0, IM_DEPTH, instr_body, 0)
 
-            @pl.when(jnp.logical_not(even))
-            def _():
-                pl.store(rf_a, (pl.ds(i, 1), slice(None)), res)
-            return 0
+    def stage_pair(p, carry):
+        run_stage(2 * p, rf_a, rf_b)
+        run_stage(2 * p + 1, rf_b, rf_a)
+        return carry
 
-        jax.lax.fori_loop(0, op_ref.shape[-1], instr_body, 0)
-        return 0
-
-    jax.lax.fori_loop(0, n_stages, stage_body, 0)
+    jax.lax.fori_loop(0, n_stages // 2, stage_pair, 0)
+    if n_stages % 2:
+        run_stage(n_stages - 1, rf_a, rf_b)
 
 
 def _tmfu_kernel(op_ref, a_ref, b_ref, imm_ref,   # scalar-prefetch (SMEM)
@@ -118,8 +183,8 @@ def _tmfu_kernel(op_ref, a_ref, b_ref, imm_ref,   # scalar-prefetch (SMEM)
                  rf_a, rf_b,                      # VMEM scratch (ping-pong)
                  *, n_stages: int, dtype):
     rf_a[...] = x_ref[...]
-    _run_stages((), op_ref, a_ref, b_ref, imm_ref, rf_a, rf_b,
-                n_stages=n_stages, dtype=dtype)
+    _run_stages(lambda s, i: (s, i), op_ref, a_ref, b_ref, imm_ref,
+                rf_a, rf_b, n_stages=n_stages, dtype=dtype)
     # after S stages the live RF is rf_a if S even else rf_b
     if n_stages % 2 == 0:
         o_ref[...] = rf_a[...]
@@ -133,15 +198,15 @@ def _tmfu_kernel_multi(ids_ref, op_ref, a_ref, b_ref, imm_ref,  # SMEM
                        *, n_stages: int, dtype):
     """Multi-tenant TMFU: grid step g executes context ``ids_ref[g]``.
 
-    The instruction bank rides in SMEM as stacked [N, S, IM] arrays; the
+    The instruction bank rides in SMEM as flattened [N, S * IM] arrays; the
     per-tile context id is a scalar-prefetch operand, so selecting a kernel
     is an SMEM row offset — the serving analogue of pointing the FU at a
     different daisy-chained context, with zero recompilation.
     """
     cid = ids_ref[pl.program_id(0)]
     rf_a[...] = x_ref[0]
-    _run_stages((cid,), op_ref, a_ref, b_ref, imm_ref, rf_a, rf_b,
-                n_stages=n_stages, dtype=dtype)
+    _run_stages(lambda s, i: (cid, s * IM_DEPTH + i), op_ref, a_ref, b_ref,
+                imm_ref, rf_a, rf_b, n_stages=n_stages, dtype=dtype)
     if n_stages % 2 == 0:
         o_ref[...] = rf_a[...][None]
     else:
@@ -166,6 +231,9 @@ def _tmfu_rf_multi(op, src_a, src_b, imm_i32, ctx_ids, x,
 
     kernel = functools.partial(_tmfu_kernel_multi, n_stages=n_stages,
                                dtype=dtype)
+    # [N, S * IM] rows fill whole SMEM tiles (see the SMEM budget above)
+    flat = [w.reshape(n_bank, n_stages * im)
+            for w in (op, src_a, src_b, imm_i32)]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -181,12 +249,12 @@ def _tmfu_rf_multi(op, src_a, src_b, imm_i32, ctx_ids, x,
         out_shape=jax.ShapeDtypeStruct((n_tiles, RF_DEPTH, tile), dtype),
         input_output_aliases={5: 0} if alias_x else {},
         interpret=interpret,
-    )(ctx_ids, op, src_a, src_b, imm_i32, x)
+    )(ctx_ids, *flat, x)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def tmfu_pipeline_rf_multi(op, src_a, src_b, imm_i32, ctx_ids, x,
-                           interpret: bool = True):
+                           *, interpret: bool):
     """Run a mixed-context tile batch: x [G, RF_DEPTH, T] -> [G, RF_DEPTH, T].
 
     op/src_a/src_b/imm_i32: stacked bank arrays [N, S, IM] int32;
@@ -200,7 +268,7 @@ def tmfu_pipeline_rf_multi(op, src_a, src_b, imm_i32, ctx_ids, x,
 @functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnums=(5,))
 def tmfu_pipeline_rf_multi_donated(op, src_a, src_b, imm_i32, ctx_ids, x,
-                                   interpret: bool = True):
+                                   *, interpret: bool):
     """``tmfu_pipeline_rf_multi`` with the tile stack donated AND aliased.
 
     The [G, RF_DEPTH, T] input has exactly the output's shape and dtype,
@@ -216,7 +284,7 @@ def tmfu_pipeline_rf_multi_donated(op, src_a, src_b, imm_i32, ctx_ids, x,
 @functools.partial(jax.jit, static_argnames=("block_batch", "interpret"))
 def tmfu_pipeline_rf(op, src_a, src_b, imm_i32, x,
                      block_batch: int = DEFAULT_BLOCK_BATCH,
-                     interpret: bool = True):
+                     *, interpret: bool):
     """Run the overlay pipeline: x [RF_DEPTH, B] -> final RF [RF_DEPTH, B].
 
     op/src_a/src_b: [S, IM_DEPTH] int32; imm_i32: int32 bit-patterns of the

@@ -185,7 +185,6 @@ class OverlayServer:
                  clock=time.monotonic, metrics_window: int = 65536,
                  device=None, slo_s=None, telemetry=None):
         from repro.core.arena import RoundArena
-        from repro.core.bank import ContextBank
         from repro.core.overlay import Overlay
         #: delivery-latency SLO target in seconds (None = no SLO
         #: accounting); a float applies to every tenant, a
@@ -214,8 +213,8 @@ class OverlayServer:
         self.overlay = Overlay(s_max=s_max, dtype=dtype, backend=backend,
                                device=device, arena=RoundArena(),
                                donate=True)
-        self.bank = ContextBank(bank_capacity, s_max=s_max, dtype=dtype,
-                                max_outputs=max_outputs, device=device)
+        self.bank = self.overlay.load_many((), capacity=bank_capacity,
+                                           max_outputs=max_outputs)
         self.bank.attach_arena(self.overlay.arena)
         self.tile = tile
         if max_inflight < 1:
@@ -764,6 +763,7 @@ class OverlayServer:
                   "queued": self.queued, "queued_tiles": self.queued_tiles,
                   "tenants": len(self._flows),
                   "round_policy": type(self.round_policy).__name__,
+                  "executor": self.overlay.executor,
                   "stage_walls": _stage_walls(self.telemetry),
                   "tenant_latency": self.tenant_latency_percentiles()})
         return s
@@ -1624,7 +1624,9 @@ def overlay_demo(argv_ns) -> int:
 
 
 def main(argv=None):
+    from repro.compile_cache import use_persistent_cache
     from repro.sched.rounds import ROUND_POLICIES
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--overlay-demo", action="store_true",

@@ -104,10 +104,12 @@ def run_training(cfg, oc, dc, *, steps: int, yield_every: int = 1,
 
 
 def main(argv=None):
+    from repro.compile_cache import use_persistent_cache
     from repro.distributed import checkpoint as C
     from repro.distributed.elastic import remesh, reshard_tree
     from repro.runtime import sharding as S
 
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

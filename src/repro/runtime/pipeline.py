@@ -28,7 +28,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -97,8 +96,8 @@ def pipeline_apply(mesh: Mesh, stage_fn, stage_params, x, *,
         return jax.lax.psum(outputs, axis)
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    return shard_map(worker, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)(stage_params, x)
+    return jax.shard_map(worker, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(stage_params, x)
 
 
 def pipeline_reference(stage_fn, stage_params, x):

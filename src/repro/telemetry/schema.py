@@ -28,10 +28,12 @@ BANK_STATS_KEYS = frozenset({
 })
 
 # OverlayServer.stats() minus the bank keys.  ``stage_walls`` nests the
-# cumulative plan_s/assemble_s/execute_s/collect_s pipeline walls.
+# cumulative plan_s/assemble_s/execute_s/collect_s pipeline walls;
+# ``executor`` names what ran the rounds ("jnp", "pallas-mosaic" or
+# "pallas-interpret").
 ENGINE_STATS_KEYS = frozenset({
     "submits", "rounds", "requests", "pending", "inflight", "queued",
-    "queued_tiles", "tenants", "round_policy", "stage_walls",
+    "queued_tiles", "tenants", "round_policy", "executor", "stage_walls",
     "tenant_latency",
 })
 

@@ -76,6 +76,32 @@ def test_dispatch_pallas_backend_matches_oracle(kernels):
     reqs = _requests(kernels, names, [200, 64, 128, 33, 256], seed=3)
     outs = ov.dispatch(bank, reqs)
     _check_against_oracle(reqs, outs, rtol=1e-5, atol=1e-5)
+    assert ov.executor == "pallas-interpret"      # CPU devices only
+
+
+def test_pallas_refuses_bank_over_smem_limit_at_construction():
+    """A bank the kernel cannot hold in SMEM is refused before any
+    compile, naming the limit; the largest that fits is accepted."""
+    from repro.kernels.tmfu.kernel import MAX_BANK_CAPACITY
+    ov = Overlay(backend="pallas")
+    assert ov.load_many((), capacity=MAX_BANK_CAPACITY).capacity == \
+        MAX_BANK_CAPACITY
+    with pytest.raises(ValueError, match=f"at most {MAX_BANK_CAPACITY}"):
+        ov.load_many((), capacity=MAX_BANK_CAPACITY + 1)
+    with pytest.raises(ValueError, match="SMEM"):
+        OverlayServer(bank_capacity=MAX_BANK_CAPACITY + 1, backend="pallas")
+    # the jnp VM keeps its bank in HBM: no such limit
+    assert Overlay().load_many((), capacity=MAX_BANK_CAPACITY + 1).capacity \
+        == MAX_BANK_CAPACITY + 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16])
+def test_pallas_refuses_packed_dtypes_at_construction(dtype):
+    with pytest.raises(ValueError, match="32-bit"):
+        Overlay(backend="pallas", dtype=dtype)
+    with pytest.raises(ValueError, match="32-bit"):
+        OverlayServer(backend="pallas", dtype=dtype)
+    assert Overlay(dtype=dtype).executor == "jnp"
 
 
 def test_vm_exec_multi_agrees_with_vm_exec(kernels):

@@ -1,7 +1,9 @@
 """TMFU Pallas kernel vs pure-jnp oracle: shape/dtype sweeps + benchmarks.
 
-Kernels run in interpret mode on CPU (the TPU is the target, not the host);
-the oracle is ref.py, cross-checked against the DFG evaluator.
+Kernels run in interpret mode here on CPU devices; the oracle is ref.py,
+cross-checked against the DFG evaluator.  Mosaic compiles of the same
+kernels for a described TPU v5e are in tests/test_chip_compile.py, and
+``chip_smoke.py`` runs them on the chip.
 """
 
 import jax
@@ -95,9 +97,8 @@ def test_kernel_traces_and_interpret_lowers():
     """Structural check: abstract-eval/trace of the pallas_call succeeds and
     the interpret path lowers inside jit.
 
-    Mosaic compilation itself requires real TPU hardware (the CPU backend
-    rejects interpret=False outright), so grid/BlockSpec coherence is
-    validated via tracing + the interpret executions above.
+    The CPU backend has no Mosaic lowering (it rejects interpret=False);
+    the Mosaic compiles for a described v5e are in test_chip_compile.py.
     """
     dfg, ctx, xs, x = _ctx_and_inputs("chebyshev", 1024, jnp.float32)
     from repro.kernels.tmfu.kernel import tmfu_pipeline_rf

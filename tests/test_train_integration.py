@@ -53,7 +53,10 @@ def test_resume_is_bitwise_deterministic(tmp_path):
 
 def test_train_launcher_with_failure_recovery(tmp_path):
     """CLI launcher: checkpoint, simulated device loss, re-mesh, resume."""
-    env = dict(os.environ, PYTHONPATH="src")
+    # the CLI turns on the persistent compile cache: keep it out of the
+    # checkout
+    env = dict(os.environ, PYTHONPATH="src",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch",

@@ -469,8 +469,11 @@ def test_cli_and_library_traces_identical(cfgs, tmp_path):
     — the refactor left no behavioural fork between the two paths."""
     steps, batch, seq, lr = 4, 2, 32, 1e-3
     trace_file = tmp_path / "trace.json"
+    # the CLI turns on the persistent compile cache: keep it out of the
+    # checkout
     env = dict(os.environ, PYTHONPATH=str(ROOT / "src"),
-               JAX_PLATFORMS="cpu")
+               JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
     env.pop("XLA_FLAGS", None)
     env.pop("JAX_DEVICES", None)
     proc = subprocess.run(
