@@ -1,0 +1,8 @@
+"""latency_p95_ms: 95th percentile of due-to-claim latency of every
+request due in the window; an undelivered request counts as infinite."""
+
+import numpy as np
+
+
+def read(run):
+    return float(np.percentile(run.latencies_s(), 95) * 1e3)
