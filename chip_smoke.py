@@ -41,6 +41,7 @@ from repro.core.paper_bench import BENCH_NAMES, benchmark  # noqa: E402
 from repro.kernels.tmfu import kernel as tmfu_kernel  # noqa: E402
 from repro.kernels.tmfu.ops import _imm_to_i32  # noqa: E402
 from repro.launch import serve  # noqa: E402
+from repro.telemetry import compile_tally  # noqa: E402
 
 KERNEL_NAMES = BENCH_NAMES + ("gradient",)
 #: the backlog: requests from tenants, lengths log-uniform in [MIN_LEN,
@@ -66,26 +67,11 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileClock:
-    """Counts XLA backend compiles and their seconds, phase by phase."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.n = 0
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == self.EVENT:
-            self.n += 1
-            self.seconds += duration
-
-    def mark(self):
-        return self.n, self.seconds
-
-    def since(self, mark) -> str:
-        return f"{self.n - mark[0]} compiles, {self.seconds - mark[1]:.2f} s"
+def since(mark) -> str:
+    """The XLA compiles this thread ran since ``mark``, a reading of
+    ``compile_tally()`` (the engine's own compile listener)."""
+    n, seconds = compile_tally()
+    return f"{n - mark[0]} compiles, {seconds - mark[1]:.2f} s"
 
 
 def cache_sizes() -> dict:
@@ -222,30 +208,29 @@ def back_to_back_dispatch(srv, wl: Workload) -> int:
 
 
 # ------------------------------------------------------------------ phases
-def serving_phase(wl: Workload, backend: str, clock: CompileClock,
-                  sync_waves: int) -> None:
+def serving_phase(wl: Workload, backend: str, sync_waves: int) -> None:
     """One backend: tile 128 warm + measured passes, the flush_sync and
     back-to-back checks, then one pass at tile 512."""
-    t0, c0 = time.perf_counter(), clock.mark()
+    t0, c0 = time.perf_counter(), compile_tally()
     srv = serve.OverlayServer(bank_capacity=8, tile=128, backend=backend)
     warm = serve_waves(srv, wl, wl.waves)
     check(wl, warm, f"{backend} tile 128 warmup")
     del warm
     log(f"[{backend}] tile 128 warmup: {len(wl.lens)} requests correct; "
-        f"{clock.since(c0)}; {time.perf_counter() - t0:.1f} s")
+        f"{since(c0)}; {time.perf_counter() - t0:.1f} s")
 
-    before, t1, c1 = cache_sizes(), time.perf_counter(), clock.mark()
+    before, t1, c1 = cache_sizes(), time.perf_counter(), compile_tally()
     results = serve_waves(srv, wl, wl.waves)
     after = cache_sizes()
     grown = {n: after[n] - v for n, v in before.items() if after[n] != v}
-    if grown or clock.n != c1[0]:
+    if grown or compile_tally()[0] != c1[0]:
         raise AssertionError(f"[{backend}] compiled after warmup: "
-                             f"{clock.since(c1)}, retraced {grown}")
+                             f"{since(c1)}, retraced {grown}")
     check(wl, results, f"{backend} tile 128")
     st = srv.stats()
     log(f"[{backend}] tile 128 measured pass: {len(results)} requests, "
         f"{wl.elements} elements, all equal to dfg_eval; retraces 0; "
-        f"{clock.since(c1)}; {time.perf_counter() - t1:.1f} s; "
+        f"{since(c1)}; {time.perf_counter() - t1:.1f} s; "
         f"rounds {st['rounds']} and evictions {st['evictions']} over both "
         f"passes, executor {st['executor']}")
     if st["executor"] != EXECUTORS[backend]:
@@ -257,7 +242,7 @@ def serving_phase(wl: Workload, backend: str, clock: CompileClock,
         assert_mosaic_kernel(srv)
         log("[pallas] compiled executable holds tpu_custom_call")
 
-    c2 = clock.mark()
+    c2 = compile_tally()
     sync_idx = [i for w in wl.waves[:sync_waves] for i in w]
     tickets = {}
     for i in sync_idx:
@@ -273,38 +258,38 @@ def serving_phase(wl: Workload, backend: str, clock: CompileClock,
     n_b2b = back_to_back_dispatch(srv, wl)
     log(f"[{backend}] flush_sync oracle: {len(sync_idx)} requests equal; "
         f"back-to-back dispatch: {n_b2b} requests equal; "
-        f"{clock.since(c2)}")
+        f"{since(c2)}")
     del results, synced
 
-    t3, c3 = time.perf_counter(), clock.mark()
+    t3, c3 = time.perf_counter(), compile_tally()
     srv512 = serve.OverlayServer(bank_capacity=8, tile=512, backend=backend)
     check(wl, serve_waves(srv512, wl, wl.waves), f"{backend} tile 512")
     st = srv512.stats()
     log(f"[{backend}] tile 512 pass: {len(wl.lens)} requests correct; "
         f"rounds {st['rounds']}, evictions {st['evictions']}; "
-        f"{clock.since(c3)}; {time.perf_counter() - t3:.1f} s")
+        f"{since(c3)}; {time.perf_counter() - t3:.1f} s")
     log(f"[{backend}] phase total {time.perf_counter() - t0:.1f} s, "
-        f"{clock.since(c0)}")
+        f"{since(c0)}")
 
 
-def cli_phase(clock: CompileClock) -> None:
+def cli_phase() -> None:
     for backend in ("pallas", "jnp"):
-        c0, t0 = clock.mark(), time.perf_counter()
+        c0, t0 = compile_tally(), time.perf_counter()
         rc = serve.main(["--overlay-demo", "--backend", backend,
                          "--bank", "4", "--requests", "36",
                          "--req-batch", "4096"])
         if rc != 0:
             raise AssertionError(f"serve.main --backend {backend} -> {rc}")
         log(f"[cli] --overlay-demo --backend {backend}: rc 0; "
-            f"{clock.since(c0)}; {time.perf_counter() - t0:.1f} s")
+            f"{since(c0)}; {time.perf_counter() - t0:.1f} s")
 
 
-def four_chip_phase(wl: Workload, clock: CompileClock) -> None:
+def four_chip_phase(wl: Workload) -> None:
     """The 4-replica fleet: distinct devices, residency on each, one drain
     and one add mid-run, bit-equal to a single-bank engine."""
     devices = jax.devices()[:4]
     for backend in ("jnp", "pallas"):
-        c0, t0 = clock.mark(), time.perf_counter()
+        c0, t0 = compile_tally(), time.perf_counter()
         single = serve.OverlayServer(bank_capacity=8, tile=128,
                                      backend=backend, device=devices[0])
         oracle = serve_waves(single, wl, wl.waves)
@@ -353,7 +338,7 @@ def four_chip_phase(wl: Workload, clock: CompileClock) -> None:
             f"{st['scale_downs']}, scale_ups {st['scale_ups']}, evictions "
             f"{st['evictions']}, executors "
             f"{sorted({p['executor'] for p in st['per_replica']})}; "
-            f"{clock.since(c0)}; {time.perf_counter() - t0:.1f} s")
+            f"{since(c0)}; {time.perf_counter() - t0:.1f} s")
 
 
 def assert_fleet_placement(fleet, drained: bool) -> None:
@@ -391,7 +376,7 @@ def main(argv=None) -> int:
         print(f"chip_smoke: needs {need} TPU devices, found {len(devices)}",
               file=sys.stderr)
         return 1
-    clock = CompileClock()
+    c0 = compile_tally()
     t0 = time.perf_counter()
     wl = Workload(N_REQUESTS, N_TENANTS, MIN_LEN, MAX_LEN, WAVE, args.seed)
     log(f"workload: {len(wl.lens)} requests, {wl.elements} elements, "
@@ -399,13 +384,12 @@ def main(argv=None) -> int:
         f"{N_TENANTS} tenants; oracle ready in "
         f"{time.perf_counter() - t0:.1f} s")
     if args.four_chips:
-        four_chip_phase(wl, clock)
+        four_chip_phase(wl)
     else:
         for backend in ("jnp", "pallas"):
-            serving_phase(wl, backend, clock, SYNC_WAVES)
-        cli_phase(clock)
-    log(f"total {time.perf_counter() - t0:.1f} s, "
-        f"{clock.n} compiles ({clock.seconds:.1f} s)")
+            serving_phase(wl, backend, SYNC_WAVES)
+        cli_phase()
+    log(f"total {time.perf_counter() - t0:.1f} s, {since(c0)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

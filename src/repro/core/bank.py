@@ -155,6 +155,8 @@ class ContextBank:
         self._key_gen: dict[tuple[str, str], int] = {}
         self.n_loads = 0
         self.n_evictions = 0
+        #: a round's kernel-group lookups that found the context resident
+        #: (``acquire``); with ``n_loads`` they add up to the groups planned
         self.n_hits = 0
         #: optional RoundArena serving this bank's rounds (attached by the
         #: engine); surfaced in stats() so a leaking arena bucket shows up
@@ -192,7 +194,6 @@ class ContextBank:
         slot = self._lru.get(key)
         if slot is not None:
             self._lru.move_to_end(key)
-            self.n_hits += 1
         return slot
 
     def meta(self, slot: int) -> dict:
@@ -211,6 +212,16 @@ class ContextBank:
         if slot is None:
             return None
         return slot, self._key_gen[key]
+
+    def acquire(self, kernel, pin: bool = False) -> int:
+        """A round's lookup of one of its kernel groups: the slot, via
+        ``pin`` or ``load``.  The one place ``hits`` counts: a lookup
+        that finds the context resident is a hit, any other is a load.
+        Probes (``slot_of``, ``peek``) and loads outside rounds
+        (``prefetch``, ``load_many``) count no hit."""
+        if context_key(getattr(kernel, "program", kernel)) in self._lru:
+            self.n_hits += 1
+        return self.pin(kernel) if pin else self.load(kernel)
 
     # -------------------------------------------------------------- pinning
     def pin(self, kernel) -> int:
@@ -290,7 +301,6 @@ class ContextBank:
         slot = self._lru.get(key)
         if slot is not None:
             self._lru.move_to_end(key)
-            self.n_hits += 1
             return slot
         ctx = self._ctx_cache.get(key)
         if ctx is None:

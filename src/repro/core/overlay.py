@@ -285,7 +285,7 @@ class Overlay:
         try:
             for key, idxs in groups.items():
                 kern = requests[idxs[0]][0]
-                slot = bank.pin(kern) if pin else bank.load(kern)
+                slot = bank.acquire(kern, pin=pin)
                 lens = [int(np.shape(requests[i][1][0])[0]) for i in idxs]
                 total = sum(lens)
                 n_tiles = -(-total // tile)

@@ -69,7 +69,8 @@ from repro.sched import (AdmissionControl, AdmissionError, AutoPump,
                          TokenBucket, WorkRequest, make_round_policy,
                          make_router)
 from repro.sched.rounds import DEFAULT_TENANT
-from repro.telemetry import InMemorySink, MultiSink, adopt_counters
+from repro.telemetry import (ROUND_STATS_KEYS, InMemorySink, MultiSink,
+                             adopt_counters, span)
 
 __all__ = [
     "AdmissionControl", "AdmissionError", "AutoPump", "DEFAULT_TENANT",
@@ -120,19 +121,31 @@ def tenant_latency_summary(samples, qs=LATENCY_QS, slo_s=None) -> dict:
 
 
 #: the staged-pipeline wall-clock counters surfaced as stats()["stage_walls"]
-STAGE_WALL_KEYS = ("plan_s", "assemble_s", "execute_s", "collect_s")
+STAGE_WALL_KEYS = ("plan_s", "assemble_s", "launch_s", "device_wait_s",
+                   "collect_s")
 
 
 def _stage_walls(telemetry) -> dict:
     """Cumulative per-stage walls of the streaming pipeline (seconds).
 
-    ``plan_s``/``assemble_s`` are pure host work, ``execute_s`` is launch
-    dispatch plus the retire-time device wait, ``collect_s`` is the
-    readback + slicing.  The ``flush_sync`` barrier oracle goes through
-    ``Overlay.dispatch`` and does not contribute.
+    ``plan_s``/``assemble_s`` are pure host work, ``launch_s`` is the
+    executor call's dispatch, ``device_wait_s`` the retire-time wait for
+    the device, ``collect_s`` the readback + slicing.  The ``flush_sync``
+    barrier oracle goes through ``Overlay.dispatch`` and does not
+    contribute.
     """
     return {k: float(telemetry.counter(f"engine.{k}"))
             for k in STAGE_WALL_KEYS}
+
+
+def _round_counters(telemetry) -> dict:
+    """The engine's per-round counters (see docs/TELEMETRY.md):
+    ``queue_wait_s`` (submit to round launch, summed over delivered
+    requests), live against executed tiles and instruction slots of the
+    streaming path's rounds, and the XLA compiles (count, seconds) its
+    rounds ran inside their launch and collect stages."""
+    return {k: float(telemetry.counter(f"engine.{k}"))
+            for k in sorted(ROUND_STATS_KEYS)}
 
 
 @dataclasses.dataclass
@@ -145,6 +158,7 @@ class _Inflight:
     round_no: int
     t_launch: float = 0.0     # engine clock at launch (RoundPolicy.observe)
     work_outs: dict | None = None   # ticket -> WorkRequest fn() output
+    compiles: int = 0         # XLA compiles run by the launch stages
 
 
 class OverlayServer:
@@ -289,18 +303,21 @@ class OverlayServer:
         tenant's token bucket cannot cover the request's tile cost.
         """
         from repro.core.bank import context_key
-        xs = list(xs)
-        cost = -(-int(np.shape(xs[0])[0]) // self.tile)
-        self.admission.admit(tenant, max(1, cost))
-        t = self._next_ticket
-        self._next_ticket += 1
-        req = OverlayRequest(ticket=t, kernel=kernel, xs=xs, tenant=tenant,
-                             key=context_key(kernel.program), cost=cost,
-                             t_submit=self.clock())
-        self._enqueue(req)
-        self._records[t] = {"tenant": tenant, "t_submit": req.t_submit,
-                            "cost": cost, "t_done": None, "round": None}
-        self.telemetry.inc("engine.submits")
+        with span(self.telemetry, "engine.submit", self.clock,
+                  ticket=self._next_ticket):
+            xs = list(xs)
+            cost = -(-int(np.shape(xs[0])[0]) // self.tile)
+            self.admission.admit(tenant, max(1, cost))
+            t = self._next_ticket
+            self._next_ticket += 1
+            req = OverlayRequest(ticket=t, kernel=kernel, xs=xs,
+                                 tenant=tenant,
+                                 key=context_key(kernel.program), cost=cost,
+                                 t_submit=self.clock())
+            self._enqueue(req)
+            self._records[t] = {"tenant": tenant, "t_submit": req.t_submit,
+                                "cost": cost, "t_done": None, "round": None}
+            self.telemetry.inc("engine.submits")
         return t
 
     def submit_work(self, fn, tenant: str = DEFAULT_TENANT, *,
@@ -401,11 +418,12 @@ class OverlayServer:
         # prune drained flows: a long-lived server over an unbounded
         # tenant-label space must not scan every tenant ever seen per
         # round (flows are recreated on the tenant's next submit)
-        for tenant in [t for t in self._rr if not self._flows[t].queue]:
-            del self._flows[tenant]
-            self._rr.remove(tenant)
-        return self.round_policy.form_round(self._flows, self._rr,
-                                            self.round_kernels)
+        with span(self.telemetry, "engine.form_round", self.clock):
+            for tenant in [t for t in self._rr if not self._flows[t].queue]:
+                del self._flows[tenant]
+                self._rr.remove(tenant)
+            return self.round_policy.form_round(self._flows, self._rr,
+                                                self.round_kernels)
 
     # ---------------------------------------------------------- work stealing
     def queued_group_keys(self) -> dict:
@@ -477,17 +495,42 @@ class OverlayServer:
         callables run host-side at launch (after the device call is
         dispatched, so host work overlaps device execution), and their
         outputs deliver through the normal ticket path at retire."""
-        from repro.core.bank import BankError
         kern_reqs = [r for r in reqs if not isinstance(r, WorkRequest)]
         work_reqs = [r for r in reqs if isinstance(r, WorkRequest)]
+        round_no = int(self.telemetry.counter("engine.rounds"))
         if not kern_reqs:
             work_outs = self._run_work(work_reqs)
-            round_no = int(self.telemetry.inc("engine.rounds")) - 1
+            self.telemetry.inc("engine.rounds")
             self._inflight.append(_Inflight(reqs=reqs, plan=None, ys=None,
                                             round_no=round_no,
                                             t_launch=self.clock(),
                                             work_outs=work_outs))
             return
+        with span(self.telemetry, "engine.launch_round", self.clock,
+                  round=round_no):
+            plan, ys, compiles = self._launch_kernels(kern_reqs, round_no)
+            work_outs = self._run_work(work_reqs) if work_reqs else None
+            self.telemetry.inc("engine.rounds")
+            self._inflight.append(_Inflight(reqs=reqs, plan=plan, ys=ys,
+                                            round_no=round_no,
+                                            t_launch=self.clock(),
+                                            work_outs=work_outs,
+                                            compiles=compiles))
+
+    def _launch_kernels(self, kern_reqs: list, round_no: int):
+        """The device stages of a launch: make room in the bank, then
+        plan, assemble and dispatch the executor.  Returns the plan, the
+        result future and the XLA compiles the three stages ran.
+
+        Stage walls cover the streaming path only (``flush_sync`` goes
+        through the dispatch oracle); the live/executed counters count
+        tiles (``g_total``/``g_pad``) and instruction slots: each request
+        element runs its kernel's live instruction words, and the
+        executor runs every slot of every stage on every padded element.
+        """
+        from repro.core.bank import BankError
+        from repro.core.isa import IM_DEPTH
+        tel, clock = self.telemetry, self.clock
         round_kernels = {r.key: r.kernel for r in kern_reqs}
         needed = sum(1 for k in round_kernels.values() if k not in self.bank)
         # retire in-flight rounds until the round's NEW contexts fit the
@@ -498,81 +541,98 @@ class OverlayServer:
                 excluding=round_kernels) < needed:
             self._retire_oldest()
         pairs = [(r.kernel, r.xs) for r in kern_reqs]
-        plan_s = 0.0
+        stages = []
         while True:
-            t0 = self.clock()
             try:
-                plan = self.overlay.plan(self.bank, pairs, tile=self.tile,
-                                         pin=True)
-                plan_s += self.clock() - t0
+                with span(tel, "engine.plan", clock, "engine.plan_s",
+                          round=round_no) as sp:
+                    stages.append(sp)
+                    plan = self.overlay.plan(self.bank, pairs,
+                                             tile=self.tile, pin=True)
                 break
             except BankError:
                 # belt-and-braces: plan unwinds its own pins on failure, so
                 # retiring one more round and retrying is always safe
-                plan_s += self.clock() - t0
                 if not self._inflight:
                     raise
                 self._retire_oldest()
-        t1 = self.clock()
-        batch = self.overlay.assemble(plan)
-        t2 = self.clock()
-        ys = self.overlay.execute(self.bank, batch)
-        # stage walls (streaming path only; flush_sync goes through the
-        # dispatch oracle): plan/assemble are host work, execute here is
-        # launch dispatch — the device wait lands in execute_s at retire
-        self.telemetry.inc("engine.plan_s", plan_s)
-        self.telemetry.inc("engine.assemble_s", t2 - t1)
-        self.telemetry.inc("engine.execute_s", self.clock() - t2)
-        work_outs = self._run_work(work_reqs) if work_reqs else None
-        round_no = int(self.telemetry.inc("engine.rounds")) - 1
-        self._inflight.append(_Inflight(reqs=reqs, plan=plan, ys=ys,
-                                        round_no=round_no,
-                                        t_launch=self.clock(),
-                                        work_outs=work_outs))
+        with span(tel, "engine.assemble", clock, "engine.assemble_s",
+                  round=round_no) as sp:
+            stages.append(sp)
+            batch = self.overlay.assemble(plan)
+        with span(tel, "engine.launch", clock, "engine.launch_s",
+                  round=round_no) as sp:
+            stages.append(sp)
+            ys = self.overlay.execute(self.bank, batch)
+        compiles = sum(st.compiles for st in stages)
+        tel.inc("engine.compiles", compiles)
+        tel.inc("engine.compile_s", sum(st.compile_s for st in stages))
+        tel.inc("engine.tiles_live", plan.g_total)
+        tel.inc("engine.tiles_executed", plan.g_pad)
+        tel.inc("engine.slots_live", sum(
+            g.total * sum(len(img.words) for img in g.kernel.program.images)
+            for g in plan.groups))
+        tel.inc("engine.slots_executed",
+                plan.g_pad * plan.tile * self.overlay.s_max * IM_DEPTH)
+        return plan, ys, compiles
 
     def _retire_oldest(self) -> list:
         """Deliver the oldest in-flight round; returns its tickets."""
         inf = self._inflight.popleft()
-        t0 = self.clock()
-        if inf.ys is not None:
-            jax.block_until_ready(inf.ys)
-        t1 = self.clock()
-        # host=True: live tiles/rows sliced device-side, one readback;
-        # per-request slicing is numpy views, never device-op dispatch
-        # (pure-work rounds have no plan and skip the device stages)
-        outs = (self.overlay.collect(inf.plan, inf.ys, host=True)
-                if inf.plan is not None else [])
-        now = self.clock()
-        self.telemetry.inc("engine.execute_s", t1 - t0)   # device wait
-        self.telemetry.inc("engine.collect_s", now - t1)
-        tickets = []
-        kern_outs = iter(outs)
-        for r in inf.reqs:
-            y = (inf.work_outs[r.ticket] if isinstance(r, WorkRequest)
-                 else next(kern_outs))
-            self._done[r.ticket] = y
-            rec = self._records[r.ticket]
-            rec["t_done"] = now
-            rec["round"] = inf.round_no
-            tickets.append(r.ticket)
-            self.telemetry.event("deliver", tenant=r.tenant, cost=r.cost,
-                                 round=inf.round_no,
-                                 latency_s=now - rec["t_submit"])
-        if inf.plan is not None:
-            inf.plan.release(self.bank)
-        round_cost = sum(r.cost for r in inf.reqs)
-        self._pending_tiles -= round_cost
-        self.telemetry.inc("engine.delivered", len(inf.reqs))
-        self.telemetry.log_step(inf.round_no, tiles=round_cost,
-                                requests=len(inf.reqs),
-                                wall_s=now - inf.t_launch)
-        # feedback edge: adaptive policies size future rounds off this.
-        # Units are per-request ceil tiles (r.cost) — the SAME units the
-        # policies budget rounds in (and flush_sync reports), never the
-        # plan's merged group tiles, or a budget-vs-observation mismatch
-        # would stall DynamicTilePolicy's growth on sub-tile requests
-        self.round_policy.observe(round_cost, now - inf.t_launch)
+        tel, clock = self.telemetry, self.clock
+        with span(tel, "engine.retire", clock, round=inf.round_no):
+            with span(tel, "engine.device_wait", clock,
+                      "engine.device_wait_s", round=inf.round_no):
+                if inf.ys is not None:
+                    jax.block_until_ready(inf.ys)
+            # host=True: live tiles/rows sliced device-side, one readback;
+            # per-request slicing is numpy views, never device-op dispatch
+            # (pure-work rounds have no plan and skip the device stages)
+            with span(tel, "engine.collect", clock, "engine.collect_s",
+                      round=inf.round_no) as sc:
+                outs = (self.overlay.collect(inf.plan, inf.ys, host=True)
+                        if inf.plan is not None else [])
+            now = clock()
+            tel.inc("engine.compiles", sc.compiles)
+            tel.inc("engine.compile_s", sc.compile_s)
+            tickets = []
+            kern_outs = iter(outs)
+            for r in inf.reqs:
+                y = (inf.work_outs[r.ticket] if isinstance(r, WorkRequest)
+                     else next(kern_outs))
+                self._done[r.ticket] = y
+                rec = self._records[r.ticket]
+                rec["t_done"] = now
+                rec["round"] = inf.round_no
+                tickets.append(r.ticket)
+                self._note_delivered(r, rec, inf.round_no, inf.t_launch, now)
+            if inf.plan is not None:
+                inf.plan.release(self.bank)
+            round_cost = sum(r.cost for r in inf.reqs)
+            self._pending_tiles -= round_cost
+            tel.inc("engine.delivered", len(inf.reqs))
+            tel.log_step(inf.round_no, tiles=round_cost,
+                         requests=len(inf.reqs), wall_s=now - inf.t_launch,
+                         compiles=inf.compiles + sc.compiles)
+            # feedback edge: adaptive policies size future rounds off
+            # this.  Units are per-request ceil tiles (r.cost) — the SAME
+            # units the policies budget rounds in (and flush_sync
+            # reports), never the plan's merged group tiles, or a
+            # budget-vs-observation mismatch would stall
+            # DynamicTilePolicy's growth on sub-tile requests
+            self.round_policy.observe(round_cost, now - inf.t_launch)
         return tickets
+
+    def _note_delivered(self, r, rec: dict, round_no: int, t_launch: float,
+                        now: float) -> None:
+        """One request's delivery telemetry: its queue wait (submit to
+        its round's launch) and the ``deliver`` event."""
+        queue_s = t_launch - rec["t_submit"]
+        self.telemetry.inc("engine.queue_wait_s", queue_s)
+        self.telemetry.event("deliver", ticket=r.ticket, tenant=r.tenant,
+                             cost=r.cost, round=round_no,
+                             latency_s=now - rec["t_submit"],
+                             queue_s=queue_s)
 
     def _fill_pipeline(self) -> None:
         while len(self._inflight) < self.max_inflight:
@@ -698,10 +758,9 @@ class OverlayServer:
                 y = (work_outs[r.ticket] if isinstance(r, WorkRequest)
                      else next(kern_outs))
                 results[r.ticket] = y
-                self._records[r.ticket].update(t_done=now, round=round_no)
-                self.telemetry.event(
-                    "deliver", tenant=r.tenant, cost=r.cost, round=round_no,
-                    latency_s=now - self._records[r.ticket]["t_submit"])
+                rec = self._records[r.ticket]
+                rec.update(t_done=now, round=round_no)
+                self._note_delivered(r, rec, round_no, t_launch, now)
             round_cost = sum(r.cost for r in reqs)
             self._pending_tiles -= round_cost
             self.telemetry.inc("engine.delivered", len(reqs))
@@ -765,6 +824,7 @@ class OverlayServer:
                   "round_policy": type(self.round_policy).__name__,
                   "executor": self.overlay.executor,
                   "stage_walls": _stage_walls(self.telemetry),
+                  **_round_counters(self.telemetry),
                   "tenant_latency": self.tenant_latency_percentiles()})
         return s
 
@@ -1557,6 +1617,7 @@ class ShardedOverlayServer:
              # replicas write through MultiSink(own, fleet), so these
              # walls aggregate the whole fleet incl. drained replicas
              "stage_walls": _stage_walls(self.telemetry),
+             **_round_counters(self.telemetry),
              "tenant_latency": self.tenant_latency_percentiles()}
         s.update(self.router.stats())
         if self.autoscaler is not None:

@@ -42,8 +42,11 @@ hanging forever (already-delivered results are still claimable first).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
+
+from repro.telemetry import InMemorySink, span
 
 
 class AutoPump:
@@ -62,7 +65,6 @@ class AutoPump:
 
     def __init__(self, server, poll_interval: float = 0.005,
                  telemetry=None):
-        from repro.telemetry import InMemorySink
         if poll_interval <= 0:
             raise ValueError(
                 f"poll_interval must be > 0, got {poll_interval}")
@@ -78,6 +80,9 @@ class AutoPump:
         self.telemetry = (telemetry if telemetry is not None
                           else getattr(server, "telemetry", None)
                           or InMemorySink())
+        #: the clock the pump's spans time with: the server's, so the
+        #: lock waits and the engine's stages read on one clock
+        self.clock = getattr(server, "clock", time.monotonic)
         #: tick observers, called AFTER every pump iteration (worked or
         #: idle) from the pump thread with the lock RELEASED — see
         #: ``add_tick_listener``
@@ -130,7 +135,8 @@ class AutoPump:
     def _run(self) -> None:
         while not self._stop.is_set():
             with self._cond:
-                worked = self.server.pump_once()
+                with span(self.telemetry, "pump.tick", self.clock):
+                    worked = self.server.pump_once()
                 self.telemetry.inc("pump.ticks")
                 if worked:
                     self.telemetry.inc("pump.rounds")
@@ -156,11 +162,25 @@ class AutoPump:
             raise RuntimeError(
                 "autopump thread died; server state may be inconsistent")
 
+    @contextlib.contextmanager
+    def _held(self, counter: str | None = None):
+        """Hold the engine lock for a client call.  A client that finds
+        it held waits in a ``pump.lock_wait`` span, whose seconds go to
+        ``counter`` when one is given."""
+        if not self._lock.acquire(blocking=False):
+            with span(self.telemetry, "pump.lock_wait", self.clock, counter):
+                self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     # ------------------------------------------------------------- clients
     def submit(self, kernel, xs, tenant=None) -> int:
-        """Thread-safe ``server.submit``; the pump serves it in background."""
+        """Thread-safe ``server.submit``; the pump serves it in background.
+        Time spent waiting for the lock adds to ``pump.submit_wait_s``."""
         kw = {} if tenant is None else {"tenant": tenant}
-        with self._lock:
+        with self._held("pump.submit_wait_s"):
             ticket = self.server.submit(kernel, xs, **kw)
         self._wake.set()
         return ticket
@@ -181,7 +201,7 @@ class AutoPump:
 
     def try_result(self, ticket: int):
         """Non-blocking thread-safe claim (see ``server.try_result``)."""
-        with self._lock:
+        with self._held():
             return self.server.try_result(ticket)
 
     def try_results(self, tickets) -> dict:
@@ -195,7 +215,7 @@ class AutoPump:
         these back out to per-ticket awaiters.
         """
         out: dict = {}
-        with self._lock:
+        with self._held():
             for t in tickets:
                 try:
                     r = self.server.try_result(t)
@@ -214,7 +234,7 @@ class AutoPump:
         of client threads can block here concurrently.
         """
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cond:
+        with self._held():
             while True:
                 out = self.server.try_result(ticket)
                 if out is not None:
@@ -268,6 +288,7 @@ class AutoPump:
         s["pump_alive"] = self._thread.is_alive()
         s["pump_listeners"] = len(self._listeners)
         s["pump_listener_errors"] = self.n_listener_errors
+        s["submit_wait_s"] = self.telemetry.counter("pump.submit_wait_s")
         return s
 
     # ------------------------------------------------------------ shutdown

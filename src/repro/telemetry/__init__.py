@@ -20,6 +20,12 @@ Sinks:
   The sharded fleet gives each replica ``MultiSink(own, fleet_sink)``
   so per-replica stats and fleet aggregates come from one write.
 
+Spans (`span`) time and trace the stages of the served path: each is a
+``jax.profiler.TraceAnnotation`` on the profiler's clock, its elapsed
+engine-clock seconds optionally added to a counter, and the XLA compiles
+its thread ran inside it (`compile_tally`, one process-wide
+``jax.monitoring`` listener).
+
 See docs/TELEMETRY.md for the naming scheme and the JSONL schema.
 """
 
@@ -31,6 +37,7 @@ from repro.telemetry.sinks import (
     adopt_counters,
     read_jsonl,
 )
+from repro.telemetry.spans import compile_tally, span
 from repro.telemetry.schema import (
     AUTOSCALER_STATS_KEYS,
     BANK_STATS_KEYS,
@@ -38,6 +45,7 @@ from repro.telemetry.schema import (
     FLEET_STATS_KEYS,
     GATEWAY_STATS_KEYS,
     PUMP_STATS_KEYS,
+    ROUND_STATS_KEYS,
     ROUTER_STATS_KEYS,
     SOCKET_STATS_KEYS,
     STEAL_STATS_KEYS,
@@ -52,12 +60,15 @@ __all__ = [
     "MultiSink",
     "adopt_counters",
     "read_jsonl",
+    "span",
+    "compile_tally",
     "check_stats",
     "BANK_STATS_KEYS",
     "ENGINE_STATS_KEYS",
     "FLEET_STATS_KEYS",
     "GATEWAY_STATS_KEYS",
     "PUMP_STATS_KEYS",
+    "ROUND_STATS_KEYS",
     "ROUTER_STATS_KEYS",
     "SOCKET_STATS_KEYS",
     "STEAL_STATS_KEYS",

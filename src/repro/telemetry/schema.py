@@ -27,15 +27,27 @@ BANK_STATS_KEYS = frozenset({
     "arena",
 })
 
+# The engine's per-round counters, in both the engine's and the fleet's
+# stats(): ``queue_wait_s`` sums submit-to-launch over delivered
+# requests; ``tiles_live``/``tiles_executed`` sum each streamed round's
+# g_total/g_pad, ``slots_live``/``slots_executed`` its live instruction
+# words x elements against the executor's g_pad x tile x S_MAX x
+# IM_DEPTH slots; ``compiles``/``compile_s`` count the XLA compiles run
+# inside rounds' launch and collect stages.
+ROUND_STATS_KEYS = frozenset({
+    "queue_wait_s", "tiles_live", "tiles_executed", "slots_live",
+    "slots_executed", "compiles", "compile_s",
+})
+
 # OverlayServer.stats() minus the bank keys.  ``stage_walls`` nests the
-# cumulative plan_s/assemble_s/execute_s/collect_s pipeline walls;
-# ``executor`` names what ran the rounds ("jnp", "pallas-mosaic" or
-# "pallas-interpret").
+# cumulative plan_s/assemble_s/launch_s/device_wait_s/collect_s pipeline
+# walls; ``executor`` names what ran the rounds ("jnp", "pallas-mosaic"
+# or "pallas-interpret").
 ENGINE_STATS_KEYS = frozenset({
     "submits", "rounds", "requests", "pending", "inflight", "queued",
     "queued_tiles", "tenants", "round_policy", "executor", "stage_walls",
     "tenant_latency",
-})
+}) | ROUND_STATS_KEYS
 
 # ResidencyRouter.stats(); WorkStealingRouter adds STEAL_STATS_KEYS.
 ROUTER_STATS_KEYS = frozenset({
@@ -62,11 +74,13 @@ FLEET_STATS_KEYS = frozenset({
     "replicas_retired", "retired_lifetime_s", "peak_replicas",
     "orphaned_results", "orphan_claims", "claims", "stage_walls",
     "tenant_latency",
-})
+}) | ROUND_STATS_KEYS
 
-# AutoPump.stats() adds these on top of the wrapped server's dict.
+# AutoPump.stats() adds these on top of the wrapped server's dict;
+# ``submit_wait_s`` is the time its submits waited for the engine lock.
 PUMP_STATS_KEYS = frozenset({
     "pump_rounds", "pump_alive", "pump_listeners", "pump_listener_errors",
+    "submit_wait_s",
 })
 
 # OverlayGateway.stats(); ``fleet`` nests the pump's stats dict.

@@ -241,7 +241,8 @@ def test_engine_stats_expose_arena_and_stage_walls(kernels):
     assert s["arena"]["checkouts"] > 0
     assert s["arena"]["outstanding"] == 0          # all rounds retired
     walls = s["stage_walls"]
-    assert set(walls) == {"plan_s", "assemble_s", "execute_s", "collect_s"}
+    assert set(walls) == {"plan_s", "assemble_s", "launch_s",
+                          "device_wait_s", "collect_s"}
     assert walls["assemble_s"] > 0 and walls["collect_s"] > 0
 
 
