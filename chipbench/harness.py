@@ -11,18 +11,26 @@ metric is found by name under the benchmark's directory:
   ``host_ms_per_round.lat``, is read by the file of its name up to the
   first dot.
 
-The window drives the program's serving entry, ``OverlayServer.submit``,
-with results delivered by ``sched.pump.AutoPump``, the drain the gateway
-sits on; the engine runs with its default executor and with the
-configuration's bank capacity and dtype.  The load generator claims each
-result as the pump delivers it.  After the window every request due in it
-is drained, and every delivered answer is compared with the plain
-reference (``chipbench/reference.py``).
+The configuration names the engine.  Without ``replicas`` it is one
+``OverlayServer``; with ``replicas: N`` it is a ``ShardedOverlayServer``
+of N replicas, one on each of the cell's chips, with the engine's default
+router (``bank_capacity`` is then each replica's).  Every other engine
+setting is the engine's default.  The window drives the engine's serving
+entry, ``submit``, with results delivered by ``sched.pump.AutoPump``, the
+drain the gateway sits on.  A fleet's traffic may also drain a replica
+and add one back at set times of the window (``events``, see
+``chipbench/traffic.py``); each runs under the engine lock, between two
+of the pump's steps.  The load generator claims each result as the pump
+delivers it.  After the window every request due in it is drained, and
+every delivered answer is compared with the plain reference
+(``chipbench/reference.py``).
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 import importlib.util
 import json
 import threading
@@ -45,6 +53,9 @@ POLL_S = 0.05
 #: a trace of a whole 51-s window of faas64.steady held no device op
 #: after its first 16 s.
 TRACE_S = 10.0
+#: the longest a fleet's warm-up of every round size may take: a first
+#: run compiles them all, on every device in turn
+WARM_SHAPES_S = 1000.0
 
 
 class BenchError(RuntimeError):
@@ -156,17 +167,48 @@ def span(name: str):
 
 
 class _Spanned:
-    """The server as the pump sees it: each ``pump_once`` in a span."""
+    """The server as the pump sees it: each ``pump_once`` in a span, and
+    before it the calls other threads posted with ``call``.
+
+    The pump holds its engine lock through every ``pump_once``, so a
+    posted call runs under that lock, between two of the pump's steps,
+    as a client's call into the pump would."""
 
     def __init__(self, server):
         self._server = server
+        self._calls: collections.deque = collections.deque()
 
     def __getattr__(self, name):
         return getattr(self._server, name)
 
+    def call(self, fn, name: str, timeout: float = 4 * DRAIN_S) -> tuple:
+        """Run ``fn(server)`` on the pump's thread at its next step, in a
+        span ``name``, and wait for it.  Returns ``fn``'s result, the
+        ``perf_counter`` at which it started and the seconds it held the
+        lock; raises what ``fn`` raised."""
+        done, box = threading.Event(), {}
+        self._calls.append((fn, name, box, done))
+        if not done.wait(timeout):
+            raise BenchError(f"{name}: the pump did not run it within "
+                             f"{timeout:.0f} s")
+        if "error" in box:
+            raise box["error"]
+        return box["result"], box["t0"], box["held_s"]
+
     def pump_once(self):
+        ran = bool(self._calls)
+        while self._calls:
+            fn, name, box, done = self._calls.popleft()
+            box["t0"] = time.perf_counter()
+            try:
+                with span(name):
+                    box["result"] = fn(self._server)
+            except Exception as e:   # handed to the caller, not the pump
+                box["error"] = e
+            box["held_s"] = time.perf_counter() - box["t0"]
+            done.set()
         with span("bench.pump_once"):
-            return self._server.pump_once()
+            return self._server.pump_once() or ran
 
 
 class LoadGenerator:
@@ -216,22 +258,35 @@ class LoadGenerator:
             self._delivered.wait(max(0.0, min(until - time.perf_counter(),
                                               POLL_S)))
 
-    def open_loop(self, reqs: list, start: float, seconds: float) -> dict:
-        """Submit each request at ``start + due``; claim until the window
-        closes.  Returns the requests still outstanding."""
+    def open_loop(self, reqs: list, start: float, seconds: float,
+                  events: list = ()) -> dict:
+        """Submit each request at ``start + due``, and call each of
+        ``events``, ``(when, fire)`` pairs in order of ``when``, at
+        ``when``; claim until the window closes.  Submits and events due
+        at one time go in that order.  Returns the requests still
+        outstanding."""
         end = start + seconds
         outstanding: dict = {}
-        i = 0
+        i = j = 0
         while True:
             now = time.perf_counter()
-            while i < len(reqs) and start + reqs[i].due <= now:
-                self._submit(reqs[i], outstanding)
-                i += 1
+            while True:
+                due = start + reqs[i].due if i < len(reqs) else np.inf
+                when = events[j][0] if j < len(events) else np.inf
+                if min(due, when) > now:
+                    break
+                if due <= when:
+                    self._submit(reqs[i], outstanding)
+                    i += 1
+                else:
+                    events[j][1]()
+                    j += 1
             if self._delivered.is_set():
                 self._claim(outstanding)
-            if i >= len(reqs) and time.perf_counter() >= end:
+            if (i >= len(reqs) and j >= len(events)
+                    and time.perf_counter() >= end):
                 return outstanding
-            self._wait(start + reqs[i].due if i < len(reqs) else end)
+            self._wait(min(due, when, end))
 
     def drain(self, outstanding: dict, deadline: float) -> None:
         while outstanding and time.perf_counter() < deadline:
@@ -257,6 +312,8 @@ class Run:
     trace: dict | None = None
     trace_end: float | None = None   # perf_counter where the trace read ends
     device: dict = dataclasses.field(default_factory=dict)
+    events: list = dataclasses.field(default_factory=list)  # Rig.events
+    window_compiles: int = 0          # XLA compiles inside the window
 
     @property
     def end(self) -> float:
@@ -371,39 +428,159 @@ def make_blocks(specs, config: dict, seed: int) -> list:
             .astype(np.float32) for s in specs]
 
 
+#: what a traffic file's event may do to a fleet
+EVENT_KINDS = ("drain", "add")
+#: bank counters that a fleet's ``stats()`` leaves per replica
+BANK_COUNTERS = ("loads", "hits")
+
+
+def fleet_size(config: dict, chips: int) -> int | None:
+    """The configuration's ``replicas``, None for one engine.  A fleet
+    holds one replica on each of the cell's chips."""
+    if "replicas" not in config:
+        return None
+    n = config["replicas"]
+    if isinstance(n, bool) or not isinstance(n, int) or n != chips:
+        raise BenchError(f"replicas {n!r}: a fleet holds one replica on "
+                         f"each of the cell's {chips} chips")
+    return n
+
+
+def make_engine(config: dict, replicas: int | None, devices: list):
+    """The configuration's engine: one ``OverlayServer``, or a
+    ``ShardedOverlayServer`` of ``replicas`` on ``devices``, each on a
+    device of its own."""
+    from repro.launch.serve import OverlayServer, ShardedOverlayServer
+    bank = int(config["bank_capacity"])
+    if replicas is None:
+        return OverlayServer(bank_capacity=bank)
+    fleet = ShardedOverlayServer(n_replicas=replicas, bank_capacity=bank,
+                                 devices=devices)
+    # the engine wraps replicas onto shared devices without a word
+    placed = len({d.id for d in fleet.devices})
+    if placed < replicas:
+        raise BenchError(f"{replicas} replicas placed on {placed} distinct "
+                         f"devices")
+    return fleet
+
+
+def replica_events(traffic: dict, replicas: int | None) -> list[dict]:
+    """The traffic's ``events`` in order of ``at``, each checked against
+    the fleet it meets then."""
+    events = traffic.get("events", [])
+    if events and replicas is None:
+        raise BenchError("the traffic's events drain and add replicas; the "
+                         "configuration has one engine")
+    for e in events:
+        if not isinstance(e, dict) or e.get("do") not in EVENT_KINDS:
+            raise BenchError(f"event {e!r}: 'do' is one of {EVENT_KINDS}")
+        at = e.get("at")
+        if (isinstance(at, bool) or not isinstance(at, (int, float))
+                or not 0.0 <= at <= 1.0):
+            raise BenchError(f"event {e!r}: 'at' is a share of the window, "
+                             f"0..1")
+        keys = {"at", "do", "replica"} if e["do"] == "drain" else {"at", "do"}
+        if set(e) != keys:
+            raise BenchError(f"event {e!r}: a {e['do']} has the keys "
+                             f"{sorted(keys)}")
+    events = sorted(events, key=lambda e: e["at"])
+    size = replicas
+    for e in events:
+        if e["do"] == "add":
+            size += 1
+            continue
+        i = e["replica"]
+        if isinstance(i, bool) or not isinstance(i, int) or not 0 <= i < size:
+            raise BenchError(f"event {e!r}: the fleet has replicas "
+                             f"0..{size - 1} then")
+        if size == 1:
+            raise BenchError(f"event {e!r}: drains the last replica")
+        size -= 1
+    return events
+
+
 class Rig:
     """The set-up of a run: the catalog compiled by the program, the
-    seeded input blocks, and one engine behind its pump, warmed up on the
-    cell's own traffic from a seed stream the window does not use."""
+    seeded input blocks, and the configuration's engine behind its pump,
+    warmed up on the cell's own traffic from a seed stream the window
+    does not use."""
 
     def __init__(self, cell: Cell, seed: int, require_tpu: bool = True,
                  log=print, use_cache: bool = True):
         if use_cache:
             setup_jax(cell.root)
-        from repro.launch.serve import OverlayServer
+        import jax
         from repro.sched.pump import AutoPump
 
         c = cell.config
         if c["dtype"] != "float32":
             raise BenchError(f"dtype {c['dtype']!r}: only float32 is served")
+        replicas = fleet_size(c, cell.chips)
+        self.event_plan = replica_events(cell.traffic, replicas)
         self.cell, self.seed, self.log = cell, seed, log
         self.device = device_info(cell.chips, require_tpu)
         self.clock = CompileClock()
         self.specs = catalog.build(c["catalog"], c["input_range"])
         kernels = [catalog.to_program(s) for s in self.specs]
         self.blocks = make_blocks(self.specs, c, seed)
-        self.server = OverlayServer(bank_capacity=int(c["bank_capacity"]))
-        self.pump = AutoPump(_Spanned(self.server))
+        self.fleet = replicas is not None
+        self.server = make_engine(c, replicas, jax.devices()[:cell.chips])
+        st = self.server.stats()
+        self.served = _Spanned(self.server)
+        self.pump = AutoPump(self.served)
         self.gen = LoadGenerator(self.pump, kernels, self.blocks)
-        log(f"executor: {self.server.stats()['executor']}; catalog "
+        #: bank counters of the replicas drained so far
+        self.retired = dict.fromkeys(BANK_COUNTERS, 0)
+        #: what each event of the last phase that fired them did
+        self.events: list[dict] = []
+        engine = (f"{replicas} replicas on "
+                  f"{', '.join(str(d) for d in self.server.devices)}; "
+                  if self.fleet else "")
+        executor = st["per_replica"][0] if self.fleet else st
+        log(f"{engine}executor: {executor['executor']}; catalog "
             f"{len(self.specs)} kernels; bank {c['bank_capacity']}; device "
             f"{self.device['kind']} x{self.device['count']}")
 
+    def stats(self) -> dict:
+        """The pump's ``stats()``; a fleet's with its bank loads and hits
+        summed over its replicas, the drained ones included.  (Events
+        fire from the thread that reads this, so none runs meanwhile.)"""
+        s = self.pump.stats()
+        if self.fleet:
+            for k in BANK_COUNTERS:
+                s[k] = sum(p[k] for p in s["per_replica"]) + self.retired[k]
+        return s
+
+    def _drain(self, fleet, i: int) -> dict:
+        bank, device = fleet.replicas[i].bank, str(fleet.devices[i])
+        out = fleet.drain_replica(i)
+        for k in BANK_COUNTERS:
+            self.retired[k] += bank.stats()[k]
+        return dict(out, device=device)
+
+    @staticmethod
+    def _add(fleet) -> dict:
+        i = fleet.add_replica()
+        return {"replica": i, "device": str(fleet.devices[i])}
+
+    def _fire(self, event: dict, start: float, when: float) -> None:
+        """Run ``event`` under the engine lock; record what it did."""
+        fired = time.perf_counter()
+        if event["do"] == "drain":
+            fn = functools.partial(self._drain, i=event["replica"])
+        else:
+            fn = self._add
+        out, t0, held = self.served.call(fn, "bench.event")
+        self.events.append(dict(event, due_s=when - start,
+                                fired_s=fired - start, lock_wait_s=t0 - fired,
+                                held_s=held, result=out))
+
     def phase(self, traffic: dict, stream: int, seconds: float,
-              on_start=None):
+              on_start=None, events: bool = False):
         """One phase of ``traffic``: its requests, the start time and the
         requests still outstanding at its close.  ``on_start()`` runs
-        just before the first submit."""
+        just before the first submit.  With ``events`` the traffic's
+        replica events fire at their times (``self.events``)."""
         c, d, n = self.cell.config, self.gen, len(self.specs)
         reqs = tr.open_schedule(traffic, n, int(c["tenants"]),
                                 int(c["block_len"]), seconds,
@@ -411,7 +588,14 @@ class Rig:
         if on_start:
             on_start()
         start = time.perf_counter()
-        return reqs, start, d.open_loop(reqs, start, seconds)
+        plan = []
+        if events:
+            self.events = []
+            for e in self.event_plan:
+                when = start + e["at"] * seconds
+                plan.append((when, functools.partial(self._fire, e, start,
+                                                     when)))
+        return reqs, start, d.open_loop(reqs, start, seconds, plan)
 
     def warm_shapes(self) -> int:
         """Serve in set-up one round of every size the window's rounds can
@@ -421,24 +605,42 @@ class Rig:
         collect per multiple of 8), so a round of a size not served here
         compiles in the window.  Each round is one request of the catalog
         kernel with the fewest inputs, through the same submit path and
-        pump as the traffic.  Returns the rounds served."""
+        pump as the traffic; in a fleet, by every replica on its own
+        device (each device compiles its own), one replica after
+        another, under the engine lock (warmed at once, a thread each, the
+        replicas contend for the compile cache's file lock).  Returns the
+        rounds served by each replica."""
         up_to = int(self.cell.traffic["warmup_tiles_up_to"])
         sizes = [t for t in (1, 2, 4) if t <= up_to] + list(
             range(8, up_to + 1, 8))
         k = min(range(len(self.specs)),
                 key=lambda i: len(self.specs[i].inputs))
-        tile = self.server.tile
+        kernel, tile = self.gen.kernels[k], self.server.tile
+
+        def inputs(t):
+            return [np.zeros(t * tile, np.float32)
+                    for _ in self.specs[k].inputs]
+
+        def serve(fleet):
+            for rep in fleet.replicas:
+                for t in sizes:
+                    rep.submit(kernel, inputs(t))
+                    rep.flush()
+
         with span("bench.warmup"):
-            for t in sizes:
-                xs = [np.zeros(t * tile, np.float32)
-                      for _ in self.specs[k].inputs]
-                self.pump.result(self.pump.submit(self.gen.kernels[k], xs),
-                                 timeout=4 * DRAIN_S)
+            if self.fleet:
+                self.served.call(serve, "bench.warmup",
+                                 timeout=WARM_SHAPES_S)
+            else:
+                for t in sizes:
+                    self.pump.result(self.pump.submit(kernel, inputs(t)),
+                                     timeout=4 * DRAIN_S)
         return len(sizes)
 
     def warm_up(self) -> int:
         """Warm the shapes, then serve ``warmup_s`` of the cell's traffic
-        and wait until idle; returns the requests served."""
+        and wait until idle; returns the requests served.  The traffic's
+        events do not fire here."""
         self.log(f"warm-up round sizes: {self.warm_shapes()} rounds")
         with span("bench.warmup"):
             reqs, _, out = self.phase(self.cell.traffic, tr.STREAM_WARMUP,
@@ -451,7 +653,7 @@ class Rig:
 
     def close(self) -> None:
         self.pump.close()
-        self.server = self.pump = self.gen = None
+        self.server = self.served = self.pump = self.gen = None
 
     def round_tiles(self) -> list:
         """Tiles of each round served so far, from the engine's step log."""
@@ -487,13 +689,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         n_warm = rig.warm_up()
         mark = rig.clock.mark()
         round0 = len(rig.round_tiles())
-        stats0 = rig.pump.stats()
+        stats0 = rig.stats()
         reqs, start, out = rig.phase(cell.traffic, tr.STREAM_WINDOW, seconds,
-                                     on_start)
+                                     on_start, events=True)
         marks["window"].__exit__(None, None, None)
         if trace:
             jax.profiler.stop_trace()
-        stats1 = rig.pump.stats()
+        stats1 = rig.stats()
         mark_end = rig.clock.mark()
         rounds = rig.round_tiles()[round0:]
         # a minute past the close, counted from when the harness is back
@@ -513,11 +715,33 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"warm-up requests {n_warm}; window requests {len(reqs)}; "
         f"largest round in the window {max(rounds, default=0)} tiles "
         f"(warmed up to {cell.traffic['warmup_tiles_up_to']})")
-    return Run(cell=cell, specs=rig.specs, blocks=rig.blocks,
-               setup_s=marks["setup_s"], seconds=seconds, start=start,
-               reqs=reqs, stats0=stats0, stats1=stats1,
-               trace_end=start + min(trace_s, seconds) if trace else None,
-               device=rig.device)
+    run = Run(cell=cell, specs=rig.specs, blocks=rig.blocks,
+              setup_s=marks["setup_s"], seconds=seconds, start=start,
+              reqs=reqs, stats0=stats0, stats1=stats1,
+              trace_end=start + min(trace_s, seconds) if trace else None,
+              device=rig.device, events=rig.events,
+              window_compiles=mark_end - mark)
+    if run.events:
+        log_events(run, log)
+    return run
+
+
+def log_events(run: Run, log) -> None:
+    """Each event's times and lock, and the latency of the requests due
+    before, between and after the events."""
+    for e in run.events:
+        log(f"event {e['do']} at {e['at']}: due {e['due_s']:.4f} s, fired "
+            f"{e['fired_s']:.4f} s, waited {e['lock_wait_s']:.6f} s for the "
+            f"lock and held it {e['held_s']:.6f} s: {e['result']}")
+    lat = run.latencies_s()
+    due = np.array([r.due for r in run.reqs])
+    cuts = [0.0] + [e["due_s"] for e in run.events] + [np.inf]
+    for a, b in zip(cuts, cuts[1:]):
+        part = lat[(due >= a) & (due < b)] * 1e3
+        if part.size:
+            log(f"requests due {a:.3f}..{min(b, run.seconds):.3f} s: "
+                f"{part.size}, p50 {np.percentile(part, 50):.4f} ms, p95 "
+                f"{np.percentile(part, 95):.4f} ms")
 
 
 def read_metrics(run: Run, entries: list) -> dict:

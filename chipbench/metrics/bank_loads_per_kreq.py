@@ -1,7 +1,7 @@
 """bank_loads_per_kreq: context loads into the ContextBank per thousand
-requests delivered over the window; each load is a real context write
-(the bank's ``hits`` counter counts some lookups twice, so no hit ratio
-is built on it)."""
+requests delivered over the window; each load is a real context write.
+In a fleet the loads of every replica's bank count, a drained one's
+included (``harness.Rig.stats``)."""
 
 
 def read(run):

@@ -1,6 +1,6 @@
 """device_idle_share: the share of the traced window in which no
 operation ran on the device, 1 - (union of device-op intervals) / window,
-in percent, averaged over the chips that ran."""
+in percent, averaged over the cell's chips."""
 
 
 def read(run):

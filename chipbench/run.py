@@ -75,10 +75,15 @@ def main(argv=None) -> int:
             if args.keep_trace:
                 tracing.save_events(args.keep_trace, events)
             run.trace = tracing.reduce(events,
-                                       max_s=min(harness.TRACE_S, args.seconds))
+                                       max_s=min(harness.TRACE_S, args.seconds),
+                                       chips=cell.chips)
             log(f"trace: {run.trace['window_s']:.3f} s traced, device busy "
                 f"{run.trace['busy_s']:.3f} s, last device op ends at "
-                f"{run.trace['device_end_s']:.3f} s")
+                f"{run.trace['device_end_s']:.3f} s; {run.trace['chips']} of "
+                f"{cell.chips} chips ran an op")
+            for chip, busy in run.trace["chip_busy_s"].items():
+                log(f"trace: {chip} busy {busy:.6f} s, idle "
+                    f"{100 * (1 - busy / run.trace['window_s']):.4f} %")
     except harness.BenchError as e:
         log(f"chipbench: {e}")
         return 1

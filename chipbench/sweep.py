@@ -12,6 +12,12 @@ close (requests due by then and not yet claimed), p50/p95 latency of the
 requests due in each half of the window, the largest round in tiles, and
 compiles in the window.  It stops after the first rate whose backlog does
 not drain within a minute.  Needs the cell's TPU chips.
+
+The traffic's replica events do not fire: for a fleet cell the sweep
+finds the knee of the steady fleet, of as many replicas as the
+configuration states.  A cell that drains one replica for part of its
+window sets its rate from the knee of the smaller fleet, swept as a
+configuration of its own (one replica and one chip fewer).
 """
 
 from __future__ import annotations

@@ -220,6 +220,42 @@ def test_load_reads_the_benchmarks_spans_from_a_profile(tmp_path):
     assert tracefile.load_events(str(fixture)) == events
 
 
+def test_load_keeps_the_programs_host_spans(tmp_path):
+    """The engine's and the pump's host spans are kept beside the
+    benchmark's own, so the idle gaps name the program's stages; other
+    host annotations are not."""
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.window"):
+        for name in ("pump.tick", "engine.collect", "other.span"):
+            with jax.profiler.TraceAnnotation(name):
+                jnp.ones(8).block_until_ready()
+    jax.profiler.stop_trace()
+    events = tracefile.load(tracefile.find_xplane(str(tmp_path)))
+    assert {e.name for e in events} == {"bench.window", "pump.tick",
+                                        "engine.collect"}
+
+
+def test_reduce_averages_busy_over_the_chips():
+    """Four device planes, as a fleet on four chips traces: ``busy_s`` is
+    the mean of each chip's, which ``chip_busy_s`` gives by plane; a chip
+    of the cell that ran no op in the window counts as idle."""
+    host = "/host:CPU"
+    events = [ev(host, "main", "bench.window", 0, 10000)]
+    for n, busy in enumerate((1000, 2000, 4000, 5000)):
+        events.append(ev(f"/device:TPU:{n}", "XLA Ops", "fusion", 0, busy))
+    s = tracefile.reduce(events, chips=4)
+    assert s["chips"] == 4
+    assert s["chip_busy_s"] == {f"/device:TPU:{n}": pytest.approx(b / 1e9)
+                                for n, b in enumerate((1000, 2000, 4000,
+                                                       5000))}
+    assert s["busy_s"] == pytest.approx(3000 / 1e9)
+    three = tracefile.reduce(events[:-1], chips=4)
+    assert three["chips"] == 3
+    assert three["busy_s"] == pytest.approx(7000 / 4 / 1e9)
+
+
 @pytest.mark.parametrize("name", [
     m["name"] for kind in ("end_to_end", "per_layer")
     for m in json.loads((ROOT / "BENCHMARK.json").read_text())[kind]])

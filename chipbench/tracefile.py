@@ -2,17 +2,20 @@
 
 The run wraps its window in a ``bench.window`` host span, and its own
 calls into the server in ``bench.*`` spans
-(``jax.profiler.TraceAnnotation``).  ``reduce`` reads the device planes
+(``jax.profiler.TraceAnnotation``); the program's own host spans are
+named ``engine.*`` and ``pump.*``.  ``reduce`` reads the device planes
 (``/device:TPU:n``): their ``XLA Ops`` line gives when an operation ran,
 their ``XLA Modules`` line which program it belonged to.  All times are
 clipped to the window.
 
-* ``busy_s``: the union of the op intervals, averaged over the chips that
-  ran any op; the idle share is ``1 - busy_s / window_s``.
+* ``busy_s``: the union of the op intervals, averaged over the cell's
+  chips (a chip that ran no op in the window counts as idle); the idle
+  share is ``1 - busy_s / window_s``.  ``chip_busy_s`` holds each busy
+  chip's, by device plane; ``chips`` counts them.
 * ``op_seconds`` / ``module_seconds``: clipped durations summed by name.
 * ``gaps``: the intervals of the window in which a chip ran no op, each
-  named by the ``bench.*`` spans open at its midpoint, one per host
-  thread (``none`` when no span was open).
+  named by the host spans open at its midpoint, the benchmark's and the
+  program's (``none`` when no span was open).
 """
 
 from __future__ import annotations
@@ -24,6 +27,8 @@ from collections import defaultdict
 WINDOW_SPAN = "bench.window"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+#: the host spans kept: the benchmark's own, the engine's and the pump's
+HOST_SPANS = ("bench.", "engine.", "pump.")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,12 +58,12 @@ def _is_device(plane: str) -> bool:
 def _keep(plane: str, line: str, name: str) -> bool:
     if _is_device(plane):
         return line in (OPS_LINE, MODULES_LINE)
-    return plane.startswith("/host:") and name.startswith("bench.")
+    return plane.startswith("/host:") and name.startswith(HOST_SPANS)
 
 
 def load(path: str) -> list[Event]:
     """The events ``reduce`` reads: device ops and modules, and the
-    benchmark's own host spans."""
+    benchmark's and the program's host spans."""
     from jax.profiler import ProfileData
     out = []
     for plane in ProfileData.from_file(path).planes:
@@ -114,8 +119,9 @@ def _clip(e: Event, t0: float, t1: float) -> tuple[float, float] | None:
 
 
 def reduce(events: list[Event], n_gaps: int = 10,
-           max_s: float | None = None) -> dict:
-    """The numbers of the window, or of its first ``max_s`` seconds."""
+           max_s: float | None = None, chips: int = 1) -> dict:
+    """The numbers of the window, or of its first ``max_s`` seconds, on
+    a cell of ``chips`` chips."""
     windows = [e for e in events if e.name == WINDOW_SPAN]
     if not windows:
         raise ValueError(f"trace holds no {WINDOW_SPAN!r} span")
@@ -142,10 +148,10 @@ def reduce(events: list[Event], n_gaps: int = 10,
         elif e.name != WINDOW_SPAN:
             spans_by_thread[e.line].append(e)
 
-    busy, gaps = [], []
+    busy, gaps = {}, []
     for chip, ivs in sorted(ops_by_chip.items()):
         merged = _union(ivs)
-        busy.append(sum(e - s for s, e in merged) / 1e9)
+        busy[chip] = sum(e - s for s, e in merged) / 1e9
         edges = [t0] + [x for iv in merged for x in iv] + [t1]
         for s, e in zip(edges[::2], edges[1::2]):
             if e > s:
@@ -160,7 +166,9 @@ def reduce(events: list[Event], n_gaps: int = 10,
         named.append(["+".join(open_spans) or "none", length / 1e9])
     return {
         "window_s": window_s,
-        "busy_s": sum(busy) / len(busy) if busy else 0.0,
+        "busy_s": (sum(busy.values()) / max(chips, len(busy)) if busy
+                   else 0.0),
+        "chip_busy_s": busy,
         "chips": len(busy),
         # where the last device op ends: short of ``window_s`` by more
         # than an idle gap when the profiler dropped the window's end

@@ -20,6 +20,14 @@ does.
   order.
 * ``warmup_s``: seconds of the same mix, from a seed stream the window
   does not use, served before the window.
+* ``events`` (a fleet's traffic only, optional): a list of
+  ``{"at": <share of the window, 0..1>, "do": "drain", "replica": <index>}``
+  and ``{"at": ..., "do": "add"}``.  At ``start + at * seconds`` of the
+  window, in order with the submits, the harness drains that replica of
+  the fleet (``ShardedOverlayServer.drain_replica``) or adds one
+  (``add_replica``, on the device that hosts the fewest replicas: a
+  drained one's), under the engine lock.  An index is the fleet's at
+  that time.  Warm-up and ``sweep.py`` do not fire them.
 
 So every seed offers the same set of gaps, sizes, kernels and tenants,
 each in an order of its own.  A request's tenant is one of the
